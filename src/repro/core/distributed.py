@@ -33,13 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# pre-0.5 releases keep shard_map under jax.experimental and have no pvary
-# (there, unmapped constants are already treated as varying)
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
 
 
 def _local_gram_allgather(B_local: jax.Array, *, model_axis: str, doc_axes) -> jax.Array:
@@ -61,7 +54,7 @@ def _local_gram_ring(
     # fori_loop body would be counted once), and the compiler can pipeline
     # step k's permute against step k+1's matmul
     acc = jnp.zeros((v_loc, v_loc * n), dtype=jnp.float32)
-    acc = _pvary(acc, tuple(doc_axes) + (model_axis,))
+    acc = jax.lax.pcast(acc, tuple(doc_axes) + (model_axis,), to="varying")
     buf = B_local
     for k in range(n):
         src = (my + k) % n  # global block id currently held in buf
@@ -97,7 +90,7 @@ def make_distributed_gram(
         kwargs["n"] = dict(mesh.shape)[model_axis]
     local = functools.partial(fn, **kwargs)
 
-    shard = _shard_map(
+    shard = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(doc_axes, model_axis),),

@@ -45,19 +45,10 @@ from repro import obs
 from repro.core.specs import REGISTRY, MethodSpec, get_spec
 from repro.core.types import DenseSink, FileSink, StatsSink
 from repro.data.corpus import Collection, CollectionStats
+from repro.runtime import device
 
 OUTPUTS = ("dense", "stats", "pairs-file", "store")
 SINK_POLICIES = ("dense", "spill", "stats")
-
-
-def _default_use_kernel() -> bool:
-    """Pallas kernels only by default on real accelerators."""
-    try:
-        import jax
-
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - jax always present in this repo
-        return False
 
 
 # ---------------------------------------------------------------------------
@@ -89,7 +80,7 @@ class CountJob:
     num_shards: int = 1
     dense_vocab_cap: int = 4096            # dense-merge threshold
     df_descending: bool = False            # term IDs are df-descending
-    use_kernel: bool | None = None         # None → auto (TPU backend only)
+    use_kernel: bool | None = None         # None → the platform decides
     method_kwargs: Mapping = dataclasses.field(default_factory=dict)
 
     def __post_init__(self):
@@ -225,8 +216,10 @@ class Planner:
         if "head" in kw and job.method == "auto":
             kw["head"] = min(kw["head"], stats.vocab_size)
         if "use_kernel" in kw and "use_kernel" not in job.method_kwargs:
+            # decided from the host's chips, never by starting a backend: a
+            # planning parent must stay off the chip its workers may need
             kw["use_kernel"] = (
-                job.use_kernel if job.use_kernel is not None else _default_use_kernel()
+                job.use_kernel if job.use_kernel is not None else device.use_kernels()
             )
         return kw
 
@@ -750,6 +743,7 @@ def _spill_worker_main(workdir, worker, params, telemetry, ready_sem,
     from repro.data.preprocess import shard_documents
     from repro.runtime.fault import SharedWorkTracker
 
+    device.configure_compile_cache()
     reg = obs.configure(enabled=True) if telemetry else obs.get_registry()
     data = np.load(os.path.join(workdir, "corpus.npz"))
     c = Collection(data["doc_ptr"], data["terms"], int(data["vocab"]))
@@ -937,6 +931,11 @@ class ParallelExecutor:
         telemetry = reg.enabled
         t_ready = time.time()
         if not tracker.finished:
+            if plan.method_kwargs.get("use_kernel"):
+                device.check_chip_owner(
+                    self.num_workers,
+                    f"ParallelExecutor running kernel method {plan.method!r}",
+                )
             params = {
                 "method": plan.method,
                 "method_kwargs": dict(plan.method_kwargs),

@@ -6,8 +6,10 @@ Posting lists are packed 32 documents per uint32 word (data/index.py
 incidence tile, no MXU involvement, exact integer counts.
 
 Grid = (M/blk_m, N/blk_n, W/blk_w), word dimension innermost/sequential, the
-(blk_m, blk_n) int32 accumulator resident in VMEM. The (blk_m, blk_n, blk_w)
-AND intermediate lives in VREG/VMEM — block sizes keep it ≤ 2 MB.
+(blk_m, blk_n) int32 accumulator resident in VMEM. The output block is
+lane-aligned (blk_m a multiple of 8, blk_n of 128), which the TPU compiler
+requires; the (blk_m, blk_n, blk_w) AND intermediate is 512 KB at the
+default (8, 128, 128) blocks.
 """
 
 from __future__ import annotations
@@ -37,8 +39,8 @@ def bitpair_kernel(
     rows_i: jax.Array,
     rows_j: jax.Array,
     *,
-    blk_m: int = 64,
-    blk_n: int = 64,
+    blk_m: int = 8,
+    blk_n: int = 128,
     blk_w: int = 128,
     interpret: bool = False,
 ) -> jax.Array:

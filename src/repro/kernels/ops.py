@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
 Each op pads its inputs to kernel block multiples, dispatches to the Pallas
-kernel (``interpret=True`` on CPU — the kernel body runs in Python for
-correctness validation; compiled Mosaic on TPU), and slices the result back.
+kernel (compiled Mosaic on a TPU; off it the interpreter runs the kernel
+body for correctness validation — ``repro.runtime.device.interpret``), and
+slices the result back.
 ``use_kernel=False`` routes to the pure-jnp oracle in ref.py — the oracle IS
 the reference semantics, so both paths are interchangeable.
 """
@@ -17,10 +18,7 @@ from repro.kernels import ref
 from repro.kernels.bitpair import bitpair_kernel
 from repro.kernels.cooc_gram import cooc_gram_kernel
 from repro.kernels.segment_cooc import segment_hist_kernel
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.runtime.device import interpret as _interpret
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int, value=0) -> jax.Array:
@@ -61,8 +59,8 @@ def bitpair_popcount(
     rows_j,
     *,
     use_kernel: bool = True,
-    blk_m: int = 64,
-    blk_n: int = 64,
+    blk_m: int = 8,
+    blk_n: int = 128,
     blk_w: int = 128,
 ) -> jax.Array:
     """Intersection counts over uint32 bitmaps (M, W), (N, W) → int32 (M, N)."""

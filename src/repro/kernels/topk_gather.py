@@ -4,19 +4,36 @@ The serving hot path (store/query.py) gathers each queried term's merged
 neighbour row from the mmap'd segments, pads the rows into a rectangular
 ``(B, L)`` tile, and ranks the ``L`` candidates per row by count, PMI, or
 Dice. The reference implementation scores the tile and calls
-``jax.lax.top_k`` in one jitted function; this kernel moves the whole
-score-and-select step into a single Pallas launch so the tile never leaves
-VMEM between scoring and selection:
+``jax.lax.top_k`` in one jitted function. Here XLA scores the tile with the
+reference's own expressions, fused with the padding, and one Pallas launch
+selects the top k, streaming the candidate axis through VMEM:
 
-    score tile (VPU)  →  k × (row-max, first-argmax, mask)  →  (B, k)
+    for each (blk_b, BLK_L) column tile of ids and scores, in order:
+        merge with the running top-k (k rounds of row-max,
+                                      first-argmax, mask)
+    →  (B, k)
 
-Selection is k rounds of masked row-max. Each round takes the running
-maximum per row and, among the slots achieving it, the **lowest column
-index** — exactly ``jax.lax.top_k``'s tie rule — then retires that slot.
-``k`` is a serving-sized constant (≤ tens), so the unrolled loop stays tiny
-compared to the O(B·L) scoring work, and results are bit-identical to the
-reference on every path (the CI edge-case suite asserts this with
-``interpret=True``).
+The scores stay outside the kernel on purpose: XLA computes ``log`` and
+float division the same way in both programs, so the scores the kernel
+returns are the reference's, bit for bit, on every backend; the kernel only
+compares them.
+
+The grid is ``(B / blk_b, L / BLK_L)``; the column axis is sequential
+("arbitrary"), and the running top-k of each row block — ids, scores and
+the column each came from — stays resident in VMEM across it. VMEM use is
+therefore set by ``BLK_L`` and ``k`` and does not grow with ``L``: a head
+term whose row holds a large share of the vocabulary compiles like any
+other.
+
+Selection is k rounds of masked row-max over the running entries plus the
+new tile. Each round takes the maximum score and, among the entries
+achieving it, the one from the **lowest column** — exactly
+``jax.lax.top_k``'s tie rule — then retires it. Running entries always come
+from earlier columns than the tile, so after the last tile the running list
+is the global top-k in ``lax.top_k`` order, and results are bit-identical
+to the reference on every path (tests/test_topk_gather.py asserts this,
+across several column tiles, with ``interpret=True``). The rounds are a
+loop, not unrolled, so the program's size does not grow with ``k``.
 
 Scores (df = document frequency, D = total documents):
     count  c(t, n)                        — exact int32 ranking
@@ -35,78 +52,110 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.runtime.device import interpret as _interpret
 
 LANE = 128  # TPU lane width: pad the candidate axis to a multiple of this
+# candidate columns per grid step: two (8, 2048) 32-bit input tiles, double
+# buffered, take 256 KB of VMEM whatever the row length
+BLK_L = 2048
 
 _INT_MIN = jnp.iinfo(jnp.int32).min
+_INT_MAX = jnp.iinfo(jnp.int32).max
+# columns of not-yet-filled running slots: above every real column (a store
+# row is shorter than 2**30), distinct per slot, so an empty slot loses every
+# tie and two empty slots never tie with each other
+_EMPTY_COL = 1 << 30
 
 
-def _score_tile(ids, cnts, df_t, df_n, *, score: str, num_docs: int):
-    """Score a padded (blk_b, L) candidate tile; same expressions (and the
-    same dtypes, op for op) as the reference scorer in store/query.py."""
+def _scores(ids, cnts, df_t, df_n, *, score: str, num_docs: int):
+    """Score a padded (B, L) candidate tile; same expressions (and the same
+    dtypes, op for op) as the reference scorer in store/query.py."""
     valid = ids >= 0
     if score == "count":
-        return jnp.where(valid, cnts, 0).astype(jnp.int32), _INT_MIN
+        return jnp.where(valid, cnts, 0).astype(jnp.int32)
     if score == "pmi":
         s = jnp.log(
             cnts.astype(jnp.float32)
             * jnp.float32(num_docs)
             / (df_t.astype(jnp.float32) * df_n.astype(jnp.float32))
         )
-        return jnp.where(valid, s, -jnp.inf), -jnp.inf
+        return jnp.where(valid, s, -jnp.inf)
     if score == "dice":
         s = 2.0 * cnts.astype(jnp.float32) / (df_t + df_n).astype(jnp.float32)
-        return jnp.where(valid, s, -jnp.inf), -jnp.inf
+        return jnp.where(valid, s, -jnp.inf)
     raise ValueError(f"unknown score {score!r}; have ('count', 'pmi', 'dice')")
 
 
 def _topk_gather_kernel(
     ids_ref,
-    cnts_ref,
-    dft_ref,
-    dfn_ref,
-    out_ids_ref,
-    out_s_ref,
+    s_ref,
+    top_ids_ref,
+    top_s_ref,
+    top_col_ref,
     *,
     k: int,
-    k_pad: int,
     score: str,
-    num_docs: int,
 ):
-    ids = ids_ref[...]  # (blk_b, L) int32, -1 padding
-    s, fill = _score_tile(
-        ids, cnts_ref[...], dft_ref[...], dfn_ref[...],
-        score=score, num_docs=num_docs,
-    )
-    blk_b, L = ids.shape
-    col = jax.lax.broadcasted_iota(jnp.int32, (blk_b, L), 1)
+    l_blk = pl.program_id(1)
+    ids = ids_ref[...]  # (blk_b, blk_l) int32, -1 padding
+    s = s_ref[...]
+    # below every score: the value of empty and retired entries
+    fill = _INT_MIN if score == "count" else -jnp.inf
+    blk_b, blk_l = ids.shape
+    k_pad = top_ids_ref.shape[1]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (blk_b, k_pad), 1)
 
-    alive = jnp.ones((blk_b, L), dtype=jnp.bool_)
-    sel_ids, sel_s = [], []
-    for _ in range(k):  # k is static and small: unrolled row-max rounds
-        masked = jnp.where(alive, s, fill)
-        m = jnp.max(masked, axis=1, keepdims=True)
-        # first (lowest-index) slot achieving the max — lax.top_k's tie rule
-        idx = jnp.min(
-            jnp.where(alive & (masked == m), col, jnp.int32(L)),
-            axis=1, keepdims=True,
-        )
-        pick = col == idx
-        sel_ids.append(jnp.max(jnp.where(pick, ids, _INT_MIN), axis=1))
-        sel_s.append(m[:, 0])
-        alive = alive & ~pick
+    # the running top-k lives in the output blocks (and the column scratch):
+    # their block index ignores the column axis, so they stay in VMEM
+    @pl.when(l_blk == 0)
+    def _init():
+        top_ids_ref[...] = jnp.full((blk_b, k_pad), -1, jnp.int32)
+        top_s_ref[...] = jnp.full((blk_b, k_pad), fill, top_s_ref.dtype)
+        top_col_ref[...] = _EMPTY_COL + slot
 
-    top_ids = jnp.stack(sel_ids, axis=1)
-    top_s = jnp.stack(sel_s, axis=1)
-    if k_pad > k:  # lane-align the output tile; the wrapper slices it off
-        top_ids = jnp.concatenate(
-            [top_ids, jnp.full((blk_b, k_pad - k), -1, top_ids.dtype)], axis=1
+    run_ids = top_ids_ref[...]
+    run_s = top_s_ref[...]
+    run_col = top_col_ref[...]
+    col = l_blk * blk_l + jax.lax.broadcasted_iota(jnp.int32, (blk_b, blk_l), 1)
+
+    def select(r, carry):
+        # a retired (or never eligible) entry carries column _INT_MAX
+        rcol, tcol, new_ids, new_s, new_col = carry
+        rm = jnp.where(rcol != _INT_MAX, run_s, fill)
+        tm = jnp.where(tcol != _INT_MAX, s, fill)
+        m = jnp.maximum(
+            jnp.max(rm, axis=1, keepdims=True), jnp.max(tm, axis=1, keepdims=True)
         )
-        top_s = jnp.concatenate(
-            [top_s, jnp.full((blk_b, k_pad - k), fill, top_s.dtype)], axis=1
+        # the lowest column achieving the max — lax.top_k's tie rule
+        c = jnp.minimum(
+            jnp.min(jnp.where(rm == m, rcol, _INT_MAX), axis=1, keepdims=True),
+            jnp.min(jnp.where(tm == m, tcol, _INT_MAX), axis=1, keepdims=True),
         )
-    out_ids_ref[...] = top_ids
-    out_s_ref[...] = top_s
+        run_pick = rcol == c
+        pick = tcol == c
+        sel = jnp.maximum(
+            jnp.max(jnp.where(run_pick, run_ids, _INT_MIN), axis=1, keepdims=True),
+            jnp.max(jnp.where(pick, ids, _INT_MIN), axis=1, keepdims=True),
+        )
+        here = slot == r
+        return (
+            jnp.where(run_pick, _INT_MAX, rcol),
+            jnp.where(pick, _INT_MAX, tcol),
+            jnp.where(here, sel, new_ids),
+            jnp.where(here, m, new_s),
+            jnp.where(here, c, new_col),
+        )
+
+    # k rounds; running slots past k are lane padding, never candidates
+    rcol = jnp.where(slot < k, run_col, _INT_MAX)
+    carry = (rcol, col, run_ids, run_s, run_col)
+    _, _, new_ids, new_s, new_col = jax.lax.fori_loop(0, k, select, carry)
+
+    top_ids_ref[...] = new_ids
+    top_s_ref[...] = new_s
+    top_col_ref[...] = new_col
 
 
 @functools.partial(
@@ -117,37 +166,35 @@ def _topk_gather(
     ids, cnts, df_t, df_n, *, num_docs, score, k, blk_b, interpret
 ):
     B, L = ids.shape
-    L_pad = max(LANE, -(-L // LANE) * LANE)
+    # one column tile when the row fits, else whole BLK_L tiles
+    tile_l = min(BLK_L, max(LANE, -(-L // LANE) * LANE))
+    L_pad = -(-L // tile_l) * tile_l
     B_pad = -(-B // blk_b) * blk_b
     ids = jnp.pad(ids, ((0, B_pad - B), (0, L_pad - L)), constant_values=-1)
     cnts = jnp.pad(cnts, ((0, B_pad - B), (0, L_pad - L)))
     df_n = jnp.pad(df_n, ((0, B_pad - B), (0, L_pad - L)), constant_values=1)
     df_t = jnp.pad(df_t, ((0, B_pad - B), (0, 0)), constant_values=1)
 
-    k_pad = max(LANE, -(-k // LANE) * LANE) if not interpret else k
-    kernel = functools.partial(
-        _topk_gather_kernel, k=k, k_pad=k_pad, score=score, num_docs=num_docs
-    )
-    s_dtype = jnp.int32 if score == "count" else jnp.float32
+    k_pad = -(-k // LANE) * LANE  # lane-aligned running top-k / output tile
+    s = _scores(ids, cnts, df_t, df_n, score=score, num_docs=num_docs)
+    kernel = functools.partial(_topk_gather_kernel, k=k, score=score)
+    row_tile = pl.BlockSpec((blk_b, tile_l), lambda b, l: (b, l))
+    top_tile = pl.BlockSpec((blk_b, k_pad), lambda b, l: (b, 0))
     top_ids, top_s = pl.pallas_call(
         kernel,
-        grid=(B_pad // blk_b,),
-        in_specs=[
-            pl.BlockSpec((blk_b, L_pad), lambda b: (b, 0)),
-            pl.BlockSpec((blk_b, L_pad), lambda b: (b, 0)),
-            pl.BlockSpec((blk_b, 1), lambda b: (b, 0)),
-            pl.BlockSpec((blk_b, L_pad), lambda b: (b, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((blk_b, k_pad), lambda b: (b, 0)),
-            pl.BlockSpec((blk_b, k_pad), lambda b: (b, 0)),
-        ],
+        grid=(B_pad // blk_b, L_pad // tile_l),
+        in_specs=[row_tile, row_tile],
+        out_specs=[top_tile, top_tile],
         out_shape=[
             jax.ShapeDtypeStruct((B_pad, k_pad), jnp.int32),
-            jax.ShapeDtypeStruct((B_pad, k_pad), s_dtype),
+            jax.ShapeDtypeStruct((B_pad, k_pad), s.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((blk_b, k_pad), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(ids, cnts, df_t, df_n)
+    )(ids, s)
     return top_ids[:B, :k], top_s[:B, :k]
 
 
@@ -176,8 +223,8 @@ def topk_gather(
         k:     neighbours to return; must be <= L.
         blk_b: query rows per grid step.
         interpret: run the Pallas interpreter instead of compiling (None =
-            auto: interpret everywhere except a real TPU backend, which is
-            how CPU CI exercises the kernel).
+            the platform decides: compiled on a TPU, interpreted elsewhere,
+            which is how CPU CI exercises the kernel).
 
     Returns:
         (top_ids (B, k) int32, top_scores (B, k) int32 or float32) — rows
@@ -201,7 +248,7 @@ def topk_gather(
     if not 1 <= k <= ids.shape[1]:
         raise ValueError(f"k={k} must be in [1, L={ids.shape[1]}]")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _interpret()
     return _topk_gather(
         ids, cnts, df_t, df_n,
         num_docs=int(num_docs), score=score, k=int(k),

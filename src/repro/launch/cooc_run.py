@@ -41,6 +41,7 @@ from repro import obs
 from repro.core.plan import CountJob, Planner
 from repro.data.corpus import collection_stats, synthetic_zipf_collection
 from repro.data.preprocess import remap_df_descending
+from repro.runtime.device import configure_compile_cache
 
 
 def run(
@@ -77,7 +78,6 @@ def run(
         dense_vocab_cap=dense_vocab_cap,
         memory_budget_pairs=memory_budget_pairs,
         df_descending=True,   # remap_df_descending above
-        use_kernel=False,     # host driver: jnp oracle paths
     )
     plan = Planner().plan(job)
     print(
@@ -159,6 +159,7 @@ def main():
              "dominates spawn cost — see docs/methods.md)",
     )
     args = ap.parse_args()
+    configure_compile_cache()
     run(
         args.docs,
         args.vocab,

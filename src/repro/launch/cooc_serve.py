@@ -82,6 +82,7 @@ import numpy as np
 from repro import obs
 from repro.core.cooc import count_to_store
 from repro.data.corpus import _zipf_probs, synthetic_zipf_collection
+from repro.runtime.device import configure_compile_cache, tpu_chips
 from repro.store import CoocServer, QueryEngine, ServerOverloaded, Store
 
 
@@ -145,6 +146,26 @@ def _build_or_open(
         f"{len(store.segment_names)} segment(s))"
     )
     return store, store_path, build_s
+
+
+def _build_in_child(*args, **kwargs) -> tuple[Store, str, float]:
+    """``_build_or_open`` in a spawned process that exits before the serving
+    workers start: the build may run kernels, and on a TPU host the chip
+    must be free for the one worker that serves from it."""
+    from concurrent.futures import ProcessPoolExecutor
+
+    from repro.store.spawn import spawn_friendly_env
+
+    with spawn_friendly_env() as ctx:
+        with ProcessPoolExecutor(1, mp_context=ctx) as pool:
+            path, build_s = pool.submit(_build_path, *args, **kwargs).result()
+    return Store.open(path), path, build_s
+
+
+def _build_path(*args, **kwargs) -> tuple[str, float]:
+    configure_compile_cache()
+    _, path, build_s = _build_or_open(*args, **kwargs)
+    return path, build_s
 
 
 def _zipf_sampler(store: Store, seed: int):
@@ -402,7 +423,10 @@ def serve(
     segment_version = (
         None if store_format is None else int(store_format.lstrip("v"))
     )
-    store, store_path, build_s = _build_or_open(
+    # one process per chip: a parent that spawns chip-using workers stays
+    # off the backend, so on a TPU host the build runs in a child
+    build = _build_in_child if workers > 0 and tpu_chips() else _build_or_open
+    store, store_path, build_s = build(
         docs, vocab, method, store_path, budget_pairs, seed,
         segment_version=segment_version, build_segments=build_segments,
     )
@@ -597,6 +621,7 @@ def main():
              "routing around its slot permanently",
     )
     args = ap.parse_args()
+    configure_compile_cache()
     serve(
         args.docs,
         args.vocab,

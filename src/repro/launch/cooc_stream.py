@@ -39,6 +39,7 @@ import threading
 import time
 
 from repro import obs
+from repro.runtime.device import configure_compile_cache
 from repro.store import CompactionDaemon, CompactionPolicy, Store
 from repro.stream import FileTailSource, StreamConfig, StreamIngestor, write_feed
 
@@ -214,6 +215,7 @@ def main():
                     help="dump Prometheus-text metrics to stderr every S "
                          "seconds (enables telemetry)")
     args = ap.parse_args()
+    configure_compile_cache()
     stream(
         args.feed,
         args.store,

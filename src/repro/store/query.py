@@ -18,10 +18,10 @@ Two interchangeable score-and-select backends (``kernel=``):
 
 * ``"numpy"``  — the jitted reference: score the tile with jnp ops and rank
   with ``jax.lax.top_k`` (XLA, any backend);
-* ``"pallas"`` — the fused Pallas launch (kernels/topk_gather.py) that keeps
-  the tile in VMEM between scoring and selection; runs under the Pallas
-  interpreter off-TPU, and is asserted **bit-identical** to the reference on
-  every edge case (tests/test_topk_gather.py).
+* ``"pallas"`` — the Pallas top-k launch (kernels/topk_gather.py) that
+  streams the XLA-scored tile through VMEM in column tiles; runs under the
+  Pallas interpreter off-TPU, and is asserted **bit-identical** to the
+  reference on every edge case (tests/test_topk_gather.py).
 
 Scores (df = document frequency, D = total documents):
     count  c(t, n)                        — exact integer top-k
@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.runtime import device
 from repro.store.requests import (
     KERNELS,
     SCORES,
@@ -99,10 +100,12 @@ class QueryEngine:
     Args:
         store: an open :class:`Store`.
         cache_rows: LRU capacity (merged neighbour rows).
-        kernel: ``"numpy"`` (jitted reference) or ``"pallas"`` (fused
-            gather/top-k kernel, bit-identical results).
-        interpret: Pallas interpreter mode; ``None`` auto-selects it off-TPU
-            so the pallas path runs (and is tested) on CPU CI.
+        kernel: ``"numpy"`` (jitted reference) or ``"pallas"`` (streaming
+            top-k kernel, bit-identical results).
+        interpret: Pallas interpreter mode; ``None`` lets the platform
+            decide (compiled on a TPU, interpreted elsewhere, so the pallas
+            path runs — and is tested — on CPU CI). The engine refuses to
+            start on the CPU of a host whose TPU another process holds.
         registry: telemetry registry for ``query/execute`` spans and
             cache/kernel-dispatch counters; ``None`` uses the process-global
             one (disabled by default — see repro/obs). Serving workers pass
@@ -130,9 +133,7 @@ class QueryEngine:
         self.store = store
         self.cache_rows = cache_rows
         self.kernel = kernel
-        self._interpret = (
-            jax.default_backend() != "tpu" if interpret is None else interpret
-        )
+        self.interpret = device.interpret() if interpret is None else interpret
         self._cache: OrderedDict[int, tuple[np.ndarray, np.ndarray]] = OrderedDict()
         self._df = store.df()
         self._num_docs = max(store.num_docs, 1)
@@ -316,7 +317,7 @@ class QueryEngine:
             top_ids, top_s = topk_gather(
                 ids, cnts, df_t, df_n,
                 num_docs=self._num_docs, score=score, k=kk,
-                interpret=self._interpret,
+                interpret=self.interpret,
             )
         else:
             top_ids, top_s = _score_topk(

@@ -217,15 +217,14 @@ def check_request_types(requests) -> None:
 
 
 def default_kernel() -> str:
-    """Backend-appropriate score-and-select kernel: the fused Pallas path on
-    TPU, the jitted reference elsewhere (off-TPU the Pallas kernel runs in
-    interpreter mode — bit-identical but slow)."""
-    try:
-        import jax
+    """Platform-appropriate score-and-select kernel: the Pallas top-k path
+    on a TPU host, the jitted reference elsewhere (off-TPU the Pallas kernel
+    runs in interpreter mode — bit-identical but slow). Read from the
+    host's chips without starting a JAX backend, so a serving parent that
+    plans for its workers stays off the chip they need."""
+    from repro.runtime.device import tpu_chips
 
-        return "pallas" if jax.default_backend() == "tpu" else "numpy"
-    except Exception:  # pragma: no cover - jax always present in this repo
-        return "numpy"
+    return "pallas" if tpu_chips() else "numpy"
 
 
 def route_term(t: int, workers: int) -> int:

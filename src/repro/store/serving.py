@@ -110,7 +110,7 @@ import time
 import numpy as np
 
 from repro import obs
-from repro.runtime import faultinject
+from repro.runtime import device, faultinject
 from repro.store.spawn import spawn_friendly_env
 from repro.store.requests import (
     NeighboursRequest,
@@ -292,7 +292,8 @@ def _worker_main(
     parent's supervisor knows exactly which requests die with this process;
     the :mod:`repro.runtime.faultinject` failpoints (stall, kill, drop)
     fire between claim and execution. A ``("ready", ...)`` stats message
-    after the store opens tells the supervisor a respawned slot is warm.
+    after the store opens tells the supervisor a respawned slot is warm,
+    and names the platform and device kind the worker computes on.
 
     Telemetry rides a private enabled :class:`repro.obs.Registry` (the
     process-global one stays disabled): per-request queue-wait and latency,
@@ -301,9 +302,12 @@ def _worker_main(
     ``stats_interval_s`` seconds (0 = never), and a ``("final", ...)`` one
     always goes out at exit — so the parent loses at most one interval of
     data if this process dies."""
+    import jax
+
     from repro.store.query import QueryEngine
     from repro.store.segments import Store
 
+    device.configure_compile_cache()
     fr = faultinject.from_env()
     reg = obs.Registry(enabled=True, max_events=10_000)
     # the registry reaches the segments too: codec/bloom counters
@@ -314,7 +318,11 @@ def _worker_main(
     )
     # the slot is warm: the supervisor clears this worker's degraded flag
     # and routed traffic returns to its own queue
-    stats_q.put(("ready", worker_id, {"incarnation": incarnation}))
+    stats_q.put(("ready", worker_id, {
+        "incarnation": incarnation,
+        "platform": device.platform(),
+        "device_kind": jax.devices()[0].device_kind,
+    }))
     stats = {k: 0 for k in _STAT_KEYS}
     c_expired = reg.counter("serving/deadline_expired")
     h_wait = reg.histogram("serving/queue_wait_s")
@@ -840,6 +848,7 @@ class CoocServer:
         )
         self._stats_final: dict = {}
         self._worker_last: dict[int, dict] = {}   # freshest payload per worker
+        self._devices: dict[int, dict] = {}       # wid -> ready-time device
         self._worker_final: set[int] = set()
         self._worker_archive: list[dict] = []     # dead incarnations' last payloads
         self._procs: list = []
@@ -865,6 +874,9 @@ class CoocServer:
     def start(self) -> "CoocServer":
         if self._started:
             raise RuntimeError("server already started")
+        # every worker runs a QueryEngine on JAX's device: on a TPU host
+        # that is one process per chip, and this process must not hold it
+        device.check_chip_owner(self.config.workers, "CoocServer")
         self._procs = []
         self._worker_final = set()
         self._stopping.clear()
@@ -1120,6 +1132,11 @@ class CoocServer:
             if inc >= cur:
                 with self._route_lock:
                     self._degraded.discard(wid)
+                with self._stats_lock:
+                    self._devices[wid] = {
+                        "platform": payload.get("platform"),
+                        "device_kind": payload.get("device_kind"),
+                    }
             return
         if inc < cur:
             return  # stale pipe data from a dead incarnation (archived)
@@ -1158,7 +1175,8 @@ class CoocServer:
         bloom negative rate — zeros on raw v1), ``metrics`` (the raw merged
         snapshot — feed it to ``repro.obs.prometheus_text``),
         ``per_worker`` (each live worker's own counters, e.g. per-worker
-        ``cache_hit_rate`` under routing)."""
+        ``cache_hit_rate`` under routing), ``devices`` (the platform and
+        device kind each ready worker reported, by worker id)."""
         if not self._started:
             return self._stats_final
         self._drain_stats_q()
@@ -1168,6 +1186,7 @@ class CoocServer:
         with self._stats_lock:
             current = {w: self._worker_last[w] for w in sorted(self._worker_last)}
             payloads = list(self._worker_archive) + list(current.values())
+            devices = {w: dict(d) for w, d in sorted(self._devices.items())}
         per_worker = {w: p["stats"] for w, p in current.items()}
         stat_dicts = [p["stats"] for p in payloads]
         agg = {
@@ -1260,6 +1279,7 @@ class CoocServer:
             "storage": storage,
             "metrics": metrics,
             "per_worker": [per_worker[w] for w in sorted(per_worker)],
+            "devices": devices,
         }
 
     # -------------------------------------------------------------- shutdown

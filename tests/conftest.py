@@ -4,6 +4,10 @@ import sys
 # Tests must see exactly ONE device (the dry-run sets its own 512-device flag
 # in a separate process). Keep threads bounded for CI stability.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# Entry points and serving workers place JAX's persistent compilation cache
+# in the checkout (repro.runtime.device.configure_compile_cache). Test runs
+# compile for the CPU from several xdist workers at once; keep them off it.
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 

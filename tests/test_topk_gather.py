@@ -10,7 +10,7 @@ import pytest
 from repro.core.cooc import count_to_store
 from repro.data.corpus import synthetic_zipf_collection
 from repro.data.preprocess import preprocess_documents
-from repro.kernels.topk_gather import topk_gather
+from repro.kernels.topk_gather import BLK_L, topk_gather
 from repro.store import QueryEngine
 from repro.store.query import _score_topk
 
@@ -82,6 +82,27 @@ def test_kernel_ties_exact_order(score):
     df_n = np.full((B, L), 5, dtype=np.int64)
     ri, _ = _assert_identical(ids, cnts, df_t, df_n, 100, score, k)
     np.testing.assert_array_equal(ri[0], np.arange(10, 10 + L))
+
+
+@pytest.mark.parametrize("score", SCORES)
+def test_kernel_merges_topk_across_column_tiles(score):
+    """Rows several column tiles long: the running top-k carried from tile
+    to tile must give lax.top_k's exact order, ties across tile borders
+    included, and k may exceed one tile's width."""
+    assert BLK_L == 2048
+    rng = np.random.default_rng(11)
+    for B, L, k in [(3, 5000, 10), (9, 4100, 200), (1, 2200, 2100)]:
+        lens = rng.integers(L // 2, L + 1, size=B)
+        lens[0] = 5  # one row ending inside the first tile
+        ids = np.full((B, L), -1, dtype=np.int64)
+        cnts = np.zeros((B, L), dtype=np.int64)
+        for b in range(B):
+            n = int(lens[b])
+            ids[b, :n] = np.sort(rng.choice(8 * L, size=n, replace=False))
+            cnts[b, :n] = rng.integers(1, 4, size=n)  # ties in every tile
+        df_t = rng.integers(1, 30, size=B)
+        df_n = np.where(ids >= 0, rng.integers(1, 30, size=(B, L)), 1)
+        _assert_identical(ids, cnts, df_t, df_n, 400, score, k)
 
 
 def test_kernel_k_bounds():
