@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import obs
 from repro.core.types import PairSink, emit_dense_rows
 from repro.data.corpus import Collection
 from repro.data.index import build_inverted_index, incidence_dense
@@ -39,34 +40,39 @@ def count_freq_split(
 
     V, D = c.vocab_size, c.num_docs
     H = min(head, V)
+    reg = obs.get_registry()
 
     # --- head × head: dense Gram over document tiles (MXU path) ---
     matmuls = 0
-    acc = np.zeros((H, H), dtype=np.int64)
-    for dlo in range(0, D, doc_tile):
-        dhi = min(dlo + doc_tile, D)
-        tile = incidence_dense(c, dlo, dhi, 0, H)
-        acc += np.asarray(kops.cooc_gram(tile, tile, use_kernel=use_kernel)).astype(np.int64)
-        matmuls += 1
-    emit_dense_rows(acc, sink, row_lo=0, col_lo=0)
+    with reg.span("ingest/count_head", head=H):
+        acc = np.zeros((H, H), dtype=np.int64)
+        for dlo in range(0, D, doc_tile):
+            dhi = min(dlo + doc_tile, D)
+            tile = incidence_dense(c, dlo, dhi, 0, H)
+            acc += np.asarray(
+                kops.cooc_gram(tile, tile, use_kernel=use_kernel)
+            ).astype(np.int64)
+            matmuls += 1
+        emit_dense_rows(acc, sink, row_lo=0, col_lo=0)
 
     # --- tail columns: tail-side LIST-SCAN histograms ---
-    inv = build_inverted_index(c)
     tail_postings = 0
-    col = np.zeros(V, dtype=np.int64)
-    for t in range(H, V):
-        post = inv.postings(t)
-        if len(post) == 0:
-            continue
-        col[:t] = 0
-        for d in post:
-            ts = c.doc(int(d))
-            lower = ts[: np.searchsorted(ts, t)]  # strictly smaller IDs
-            col[lower] += 1
-            tail_postings += 1
-        nz = np.nonzero(col[:t])[0]
-        if len(nz):
-            sink.emit_col(t, nz, col[nz])
+    with reg.span("ingest/count_tail", terms=V - H):
+        inv = build_inverted_index(c)
+        col = np.zeros(V, dtype=np.int64)
+        for t in range(H, V):
+            post = inv.postings(t)
+            if len(post) == 0:
+                continue
+            col[:t] = 0
+            for d in post:
+                ts = c.doc(int(d))
+                lower = ts[: np.searchsorted(ts, t)]  # strictly smaller IDs
+                col[lower] += 1
+                tail_postings += 1
+            nz = np.nonzero(col[:t])[0]
+            if len(nz):
+                sink.emit_col(t, nz, col[nz])
     return {
         "head": H,
         "head_matmuls": matmuls,

@@ -61,7 +61,10 @@ def run(
 ) -> dict:
     os.makedirs(out_dir, exist_ok=True)
     telemetry = bool(trace_out) or metrics_interval > 0
-    reg = obs.configure(enabled=True) if telemetry else obs.get_registry()
+    reg = (
+        obs.configure(enabled=True, annotate=bool(trace_out))
+        if telemetry else obs.get_registry()
+    )
     c = synthetic_zipf_collection(num_docs, vocab=vocab, mean_len=60, seed=0)
     cd, _ = remap_df_descending(c)
     print(f"[corpus] {collection_stats(cd)}")

@@ -419,7 +419,10 @@ def serve(
     supervisor's replacement budget per dead worker (multi-process
     topology only — docs/serving.md#degradation--recovery)."""
     telemetry = bool(trace_out) or metrics_interval > 0
-    reg = obs.configure(enabled=True) if telemetry else obs.get_registry()
+    reg = (
+        obs.configure(enabled=True, annotate=bool(trace_out))
+        if telemetry else obs.get_registry()
+    )
     segment_version = (
         None if store_format is None else int(store_format.lstrip("v"))
     )

@@ -98,7 +98,10 @@ def stream(
     """Tail ``feed`` into ``store_path`` until done (max_docs reached, or
     idle for idle_timeout_s); returns the run summary dict."""
     telemetry = bool(trace_out) or metrics_interval > 0
-    reg = obs.configure(enabled=True) if telemetry else obs.get_registry()
+    reg = (
+        obs.configure(enabled=True, annotate=bool(trace_out))
+        if telemetry else obs.get_registry()
+    )
 
     if Store.exists(store_path):
         store = Store.open(store_path, registry=reg)

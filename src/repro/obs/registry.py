@@ -14,6 +14,13 @@ attributes, and land in the event log as Chrome ``trace_event``-shaped
 records; exporters (obs/export.py) turn the log into a ``chrome://tracing``
 / Perfetto file and the metric tables into Prometheus text.
 
+Two options shape what a span leaves behind. ``annotate=True`` makes each
+span also enter a ``jax.profiler.TraceAnnotation`` of the same name, so a
+JAX profile of the process shows the span on the profiler's own clock,
+beside the device's operations. ``log=False`` keeps no event log at all
+(and counts no drops): a long-lived process whose spans only ever matter
+in a profile pays for the annotation and nothing more.
+
 Example::
 
     reg = Registry(enabled=True)
@@ -76,7 +83,7 @@ class Span:
     attributes mid-flight (e.g. a result count known only at the end).
     """
 
-    __slots__ = ("_reg", "name", "attrs", "_t0", "_depth")
+    __slots__ = ("_reg", "name", "attrs", "_t0", "_depth", "_ann")
 
     def __init__(self, reg: "Registry", name: str, attrs: dict):
         self._reg = reg
@@ -84,18 +91,29 @@ class Span:
         self.attrs = attrs
         self._t0 = 0.0
         self._depth = 0
+        self._ann = None
 
     def __enter__(self) -> "Span":
-        stack = self._reg._stack()
-        self._depth = len(stack)
-        stack.append(self.name)
-        self._t0 = time.perf_counter()
+        reg = self._reg
+        if reg._annotation is not None:
+            # the attributes known at entry ride as annotation arguments
+            self._ann = reg._annotation(self.name, **self.attrs)
+            self._ann.__enter__()
+        if reg.log:
+            stack = reg._stack()
+            self._depth = len(stack)
+            stack.append(self.name)
+            self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        end = time.perf_counter()
-        self._reg._stack().pop()
-        self._reg._record_span(self, self._t0, end - self._t0)
+        reg = self._reg
+        if reg.log:
+            end = time.perf_counter()
+            reg._stack().pop()
+            reg._record_span(self, self._t0, end - self._t0)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
     def set(self, **attrs) -> None:
@@ -114,12 +132,27 @@ class Registry:
 
     A disabled registry (``enabled=False``) hands out shared no-op objects:
     the instrumented code paths run, but record nothing and allocate
-    nothing.
+    nothing. ``annotate=True`` mirrors every span of an enabled registry
+    as a ``jax.profiler.TraceAnnotation`` of the same name (JAX is imported
+    only then); ``log=False`` keeps no span event log and counts no drops.
     """
 
-    def __init__(self, *, enabled: bool = True, max_events: int = 200_000):
+    def __init__(
+        self,
+        *,
+        enabled: bool = True,
+        max_events: int = 200_000,
+        annotate: bool = False,
+        log: bool = True,
+    ):
         self.enabled = enabled
         self.max_events = max_events
+        self.log = log
+        self._annotation = None
+        if enabled and annotate:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation
         self._counters: dict[str, Counter] = {}
         self._gauges: dict[str, Gauge] = {}
         self._histograms: dict[str, Histogram] = {}
@@ -250,7 +283,7 @@ class Registry:
             self.histogram(name).merge(Histogram.from_state(state))
         self.dropped_events += snapshot.get("dropped_events", 0)
         events = snapshot.get("events") or []
-        if events:
+        if events and self.log:
             shift_us = (
                 snapshot.get("epoch_unix", self.epoch_unix) - self.epoch_unix
             ) * 1e6
@@ -300,10 +333,11 @@ def set_registry(reg: Registry) -> Registry:
     return reg
 
 
-def configure(*, enabled: bool = True, max_events: int = 200_000) -> Registry:
+def configure(*, enabled: bool = True, annotate: bool = False) -> Registry:
     """Install (and return) a fresh global registry — how the drivers turn
-    telemetry on for ``--trace-out`` / ``--metrics-interval``."""
-    return set_registry(Registry(enabled=enabled, max_events=max_events))
+    telemetry on for ``--trace-out`` / ``--metrics-interval`` (with
+    ``annotate`` for ``--trace-out``, so a JAX profile shows the spans)."""
+    return set_registry(Registry(enabled=enabled, annotate=annotate))
 
 
 @contextlib.contextmanager

@@ -129,9 +129,12 @@ def _write_segment_files(
     pend_cols: list[np.ndarray] = []
     pend_cnts: list[np.ndarray] = []
     pending = 0
-    with open(os.path.join(out_dir, "cols.bin"), "wb") as fc, open(
-        os.path.join(out_dir, "counts.bin"), "wb"
-    ) as fn:
+    reg = obs.get_registry()
+    # the upper CSR rows, streamed out of the lazy bucket merge (its
+    # ingest/bucket_merge spans nest here), and the final flush
+    with reg.span("ingest/segment_rows"), open(
+        os.path.join(out_dir, "cols.bin"), "wb"
+    ) as fc, open(os.path.join(out_dir, "counts.bin"), "wb") as fn:
         def _flush_pending():
             nonlocal pending
             if pending:
@@ -169,10 +172,11 @@ def _write_segment_files(
         df = np.zeros(V, dtype=np.int64)
     _write_array(os.path.join(out_dir, "df.bin"), df, np.int64)
 
-    _write_symmetric(
-        out_dir, row_ptr, V, nnz,
-        chunk_pairs=sym_chunk_pairs or SYM_CHUNK_PAIRS,
-    )
+    with reg.span("ingest/segment_symmetric", nnz=nnz):
+        _write_symmetric(
+            out_dir, row_ptr, V, nnz,
+            chunk_pairs=sym_chunk_pairs or SYM_CHUNK_PAIRS,
+        )
 
     meta = {
         "magic": SEGMENT_MAGIC,

@@ -106,7 +106,7 @@ class QueryEngine:
             decide (compiled on a TPU, interpreted elsewhere, so the pallas
             path runs — and is tested — on CPU CI). The engine refuses to
             start on the CPU of a host whose TPU another process holds.
-        registry: telemetry registry for ``query/execute`` spans and
+        registry: telemetry registry for ``query/*`` spans and
             cache/kernel-dispatch counters; ``None`` uses the process-global
             one (disabled by default — see repro/obs). Serving workers pass
             their own so metrics can cross the process boundary.
@@ -294,43 +294,52 @@ class QueryEngine:
     def _topk_batch(
         self, terms: np.ndarray, k: int, score: str
     ) -> tuple[np.ndarray, np.ndarray]:
-        """The batched gather + score + select launch (validated inputs)."""
-        rows = [self._row(int(t)) for t in terms]
+        """The batched gather + score + select launch (validated inputs),
+        in three spans: ``query/gather`` (rows through the cache),
+        ``query/pad`` (the padded host arrays) and ``query/device`` (the
+        launch through the results back on the host)."""
+        reg = self.registry
+        B = len(terms)
+        with reg.span("query/gather", terms=B):
+            rows = [self._row(int(t)) for t in terms]
         L = max((len(r[0]) for r in rows), default=0)
         # jit cache friendliness: round the pad length up to a power of two
         L = max(8, 1 << (L - 1).bit_length()) if L else 8
-        B = len(terms)
-        ids = np.full((B, L), -1, dtype=np.int64)
-        cnts = np.zeros((B, L), dtype=np.int64)
-        for b, (rids, rcnts) in enumerate(rows):
-            ids[b, : len(rids)] = rids
-            cnts[b, : len(rids)] = rcnts
-        # clamp BOTH df sides to >=1: stores built without df metadata
-        # (write_segment df=None) would otherwise divide by zero and tie
-        # every pmi candidate at +inf
-        df_n = np.where(ids >= 0, np.maximum(self._df[np.maximum(ids, 0)], 1), 1)
-        df_t = np.maximum(self._df[terms], 1)
+        with reg.span("query/pad", width=L):
+            ids = np.full((B, L), -1, dtype=np.int64)
+            cnts = np.zeros((B, L), dtype=np.int64)
+            for b, (rids, rcnts) in enumerate(rows):
+                ids[b, : len(rids)] = rids
+                cnts[b, : len(rids)] = rcnts
+            # clamp BOTH df sides to >=1: stores built without df metadata
+            # (write_segment df=None) would otherwise divide by zero and tie
+            # every pmi candidate at +inf
+            df_n = np.where(
+                ids >= 0, np.maximum(self._df[np.maximum(ids, 0)], 1), 1
+            )
+            df_t = np.maximum(self._df[terms], 1)
         kk = min(k, L)
-        if self.kernel == "pallas":
-            from repro.kernels.topk_gather import topk_gather
+        with reg.span("query/device"):
+            if self.kernel == "pallas":
+                from repro.kernels.topk_gather import topk_gather
 
-            top_ids, top_s = topk_gather(
-                ids, cnts, df_t, df_n,
-                num_docs=self._num_docs, score=score, k=kk,
-                interpret=self.interpret,
-            )
-        else:
-            top_ids, top_s = _score_topk(
-                jnp.asarray(ids),
-                jnp.asarray(cnts),
-                jnp.asarray(df_t),
-                jnp.asarray(df_n),
-                self._num_docs,
-                score=score,
-                k=kk,
-            )
-        top_ids = np.asarray(top_ids)
-        top_s = np.asarray(top_s)
+                top_ids, top_s = topk_gather(
+                    ids, cnts, df_t, df_n,
+                    num_docs=self._num_docs, score=score, k=kk,
+                    interpret=self.interpret,
+                )
+            else:
+                top_ids, top_s = _score_topk(
+                    jnp.asarray(ids),
+                    jnp.asarray(cnts),
+                    jnp.asarray(df_t),
+                    jnp.asarray(df_n),
+                    self._num_docs,
+                    score=score,
+                    k=kk,
+                )
+            top_ids = np.asarray(top_ids)
+            top_s = np.asarray(top_s)
         if k > top_ids.shape[1]:  # fewer candidates than k: pad out
             pad = k - top_ids.shape[1]
             top_ids = np.pad(top_ids, ((0, 0), (0, pad)), constant_values=-1)

@@ -297,7 +297,10 @@ def _worker_main(
 
     Telemetry rides a private enabled :class:`repro.obs.Registry` (the
     process-global one stays disabled): per-request queue-wait and latency,
-    per-batch execute time and size, query counters via the engine. A
+    per-batch execute time and size, query counters via the engine. Its
+    spans (``serving/batch`` from claim to last response, the engine's
+    ``query/*`` stages inside) keep no event log: they exist only as
+    ``jax.profiler`` annotations, for a profile of this process. A
     ``("snap", id, payload)`` snapshot goes on the stats queue at most every
     ``stats_interval_s`` seconds (0 = never), and a ``("final", ...)`` one
     always goes out at exit — so the parent loses at most one interval of
@@ -309,7 +312,7 @@ def _worker_main(
 
     device.configure_compile_cache()
     fr = faultinject.from_env()
-    reg = obs.Registry(enabled=True, max_events=10_000)
+    reg = obs.Registry(enabled=True, annotate=True, log=False)
     # the registry reaches the segments too: codec/bloom counters
     # (blocks decoded, cache hits, bloom negatives) ride the same snapshots
     engine = QueryEngine(
@@ -406,28 +409,31 @@ def _worker_main(
         if not live:
             continue
         batch = live
-        # claim before executing: if this process dies mid-batch the
-        # supervisor fails exactly these tags — queued-but-unclaimed
-        # envelopes survive for the respawned worker
-        response_q.put((
-            "claim", worker_id, incarnation,
-            [(it[0], it[1], it[2], it[3]) for it in batch],
-        ))
-        if fr:
-            stall = fr.stall_queue(worker=worker_id)
-            if stall:
-                time.sleep(stall)
-            if fr.kill_worker(worker=worker_id, batches_done=stats["batches"]):
-                faultinject.kill_self()
-        # queue wait = batch start minus client submit; unix time is the one
-        # clock both processes share (perf_counter epochs differ per process)
-        t_start = time.time()
-        for item in batch:
-            t_sub, _dl = envelope_times(item)
-            if t_sub is not None:
-                h_wait.record(max(t_start - t_sub, 0.0))
-        t0 = time.perf_counter()
-        _serve_batch(engine, batch, serve_chan, worker_id, stats)
+        with reg.span("serving/batch", seq=stats["batches"],
+                      requests=len(batch)):
+            # claim before executing: if this process dies mid-batch the
+            # supervisor fails exactly these tags — queued-but-unclaimed
+            # envelopes survive for the respawned worker
+            response_q.put((
+                "claim", worker_id, incarnation,
+                [(it[0], it[1], it[2], it[3]) for it in batch],
+            ))
+            if fr:
+                stall = fr.stall_queue(worker=worker_id)
+                if stall:
+                    time.sleep(stall)
+                if fr.kill_worker(worker=worker_id,
+                                  batches_done=stats["batches"]):
+                    faultinject.kill_self()
+            # queue wait = batch start minus client submit; unix time is the
+            # one clock both processes share (perf_counter epochs differ)
+            t_start = time.time()
+            for item in batch:
+                t_sub, _dl = envelope_times(item)
+                if t_sub is not None:
+                    h_wait.record(max(t_start - t_sub, 0.0))
+            t0 = time.perf_counter()
+            _serve_batch(engine, batch, serve_chan, worker_id, stats)
         h_exec.record(time.perf_counter() - t0)
         h_bsz.record(len(batch))
         reg.gauge("serving/batch_window_occupancy").set(

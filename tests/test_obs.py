@@ -199,6 +199,23 @@ def test_span_event_cap_counts_drops():
     assert reg.snapshot()["dropped_events"] == 3
 
 
+def test_registry_without_log_keeps_no_events():
+    """``log=False`` keeps no span log and counts no drops, however many
+    spans run; metrics and absorbed snapshots' metrics still land."""
+    reg = obs.Registry(enabled=True, log=False)
+    for _ in range(5):
+        with reg.span("serving/batch", seq=0) as sp:
+            sp.set(requests=1)
+            reg.counter("c").inc()
+    reg.absorb({"counters": {"c": 2},
+                "events": [{"name": "x", "ts_us": 0.0, "dur_us": 1.0}]})
+    assert reg.span_events() == []
+    assert reg.dropped_events == 0
+    assert reg.stage_totals() == {}
+    assert reg.snapshot()["dropped_events"] == 0
+    assert reg.snapshot()["counters"] == {"c": 7}
+
+
 def test_scoped_installs_and_restores():
     before = obs.get_registry()
     with obs.scoped() as reg:
@@ -398,6 +415,79 @@ def test_query_engine_spans_and_cache_counters(tmp_path, coll):
     assert snap["counters"]["query.cache_hits"] >= 8  # second pass was warm
 
 
+@pytest.mark.parametrize("kernel", ["numpy", "pallas"])
+def test_query_engine_topk_stage_spans_in_order(tmp_path, coll, kernel):
+    """One top-k launch emits query/gather, query/pad and query/device, one
+    after the other, inside its query/execute span (the pallas kernel runs
+    interpreted on the CPU)."""
+    from repro.core.cooc import count_to_store
+    from repro.store import QueryEngine, TopKRequest
+
+    store, _ = count_to_store("list-scan", coll, str(tmp_path / "store"))
+    reg = obs.Registry(enabled=True)
+    engine = QueryEngine(store, kernel=kernel, registry=reg)
+    engine.execute([TopKRequest(np.arange(4), k=3, score="pmi")])
+    events = sorted(reg.span_events(), key=lambda e: e["ts_us"])
+    assert [e["name"] for e in events] == [
+        "query/execute", "query/gather", "query/pad", "query/device",
+    ]
+    root, stages = events[0], events[1:]
+    assert all(e["depth"] == root["depth"] + 1 for e in stages)
+    for a, b in zip(stages, stages[1:]):  # in order, never overlapping
+        assert a["ts_us"] + a["dur_us"] <= b["ts_us"] + 1.0
+    assert stages[-1]["ts_us"] + stages[-1]["dur_us"] <= (
+        root["ts_us"] + root["dur_us"] + 1.0
+    )
+    assert stages[0]["args"] == {"terms": 4}
+    assert stages[1]["args"]["width"] >= 8
+
+
+def _inside(child: dict, parent: dict) -> bool:
+    return (parent["ts_us"] <= child["ts_us"] + 1.0
+            and child["ts_us"] + child["dur_us"]
+            <= parent["ts_us"] + parent["dur_us"] + 1.0
+            and child["depth"] > parent["depth"])
+
+
+def test_freq_split_store_build_stage_spans_nest(tmp_path, coll):
+    """A freq-split store build splits ingest/count into its head and tail
+    stages, and ingest/segment_write into the row stream (with the lazy
+    bucket merges inside it) and the symmetric adjacency."""
+    from repro.core.plan import CountJob, Planner
+    from repro.data.preprocess import remap_df_descending
+
+    cd, _ = remap_df_descending(coll)
+    job = CountJob(
+        collection=cd, output="store", method="freq-split",
+        out_path=str(tmp_path / "store"), dense_vocab_cap=1,
+        memory_budget_pairs=256, df_descending=True,
+        method_kwargs={"head": 32, "use_kernel": False},
+    )
+    with obs.scoped() as reg:
+        res = Planner().plan(job).execute(out_dir=str(tmp_path / "run"))
+    assert res.summary["exact"] is True
+    events = reg.span_events()
+    by = {}
+    for e in events:
+        by.setdefault(e["name"], []).append(e)
+
+    def under(child: str, parent: str) -> None:
+        assert by.get(child), (child, sorted(by))
+        for c in by[child]:
+            assert any(_inside(c, p) for p in by[parent]), (child, parent)
+
+    under("ingest/count_head", "ingest/count")
+    under("ingest/count_tail", "ingest/count")
+    under("ingest/segment_rows", "ingest/segment_write")
+    under("ingest/segment_symmetric", "ingest/segment_write")
+    under("ingest/bucket_merge", "ingest/segment_rows")
+    # head before tail, rows before the symmetric build
+    assert by["ingest/count_head"][0]["ts_us"] < by["ingest/count_tail"][0]["ts_us"]
+    assert (by["ingest/segment_rows"][0]["ts_us"]
+            < by["ingest/segment_symmetric"][0]["ts_us"])
+    assert by["ingest/count_head"][0]["args"] == {"head": 32}
+
+
 def test_query_engine_private_registry_overrides_global():
     # serving workers hand the engine their own registry; the global one
     # (disabled here) must not see anything
@@ -413,3 +503,119 @@ def test_query_engine_private_registry_overrides_global():
     assert engine.registry is private
     engine._registry = None
     assert engine.registry is obs.get_registry()
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock: every jax.profiler trace of the suite is
+# recorded here, one per test
+# ---------------------------------------------------------------------------
+
+
+def _profile(trace_dir, fn) -> list:
+    """Run ``fn`` under one ``jax.profiler`` trace; returns the trace's host
+    events as (name, start_ns, end_ns, stats) read back by ProfileData."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(
+        str(trace_dir / "plugins" / "profile" / "*" / "*.xplane.pb")
+    )
+    return [
+        (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+    ]
+
+
+def test_annotated_spans_reach_the_profiler(tmp_path):
+    """With ``annotate`` on, spans show in a JAX profile under their exact
+    names, nested inside their parents, with entry attributes as stats;
+    with it off, or the registry disabled, nothing shows."""
+    on = obs.Registry(enabled=True, annotate=True)
+    off = obs.Registry(enabled=True)
+    disabled = obs.Registry(enabled=False, annotate=True)
+
+    def work():
+        with on.span("ann/outer", seq=3, requests=2):
+            with on.span("ann/inner"):
+                pass
+            with off.span("ann/off"):
+                pass
+            with disabled.span("ann/disabled"):
+                pass
+
+    events = [e for e in _profile(tmp_path / "trace", work)
+              if e[0].startswith("ann/")]
+    assert sorted(e[0] for e in events) == ["ann/inner", "ann/outer"]
+    outer = next(e for e in events if e[0] == "ann/outer")
+    inner = next(e for e in events if e[0] == "ann/inner")
+    assert outer[1] <= inner[1] and inner[2] <= outer[2]
+    assert outer[3] == {"seq": 3, "requests": 2}
+    # annotating leaves the registry's own log as it was
+    assert [e["name"] for e in on.span_events()] == ["ann/inner", "ann/outer"]
+    assert [e["name"] for e in off.span_events()] == ["ann/off"]
+
+
+def test_serving_worker_spans_reach_the_profiler(tmp_path, coll, monkeypatch):
+    """The serving worker's micro-batches show in a profile of its process
+    as serving/batch annotations, each holding query/gather, query/pad and
+    query/device in that order; its registry keeps no span log."""
+    import queue
+
+    from repro.core.cooc import count_to_store
+    from repro.store import TopKRequest
+    from repro.store import serving
+    from repro.store.requests import make_envelope
+
+    store_path = str(tmp_path / "store")
+    count_to_store("list-scan", coll, store_path)
+    made = []
+
+    class Spy(obs.Registry):
+        def __init__(self, **kw):
+            super().__init__(**kw)
+            made.append(self)
+
+    monkeypatch.setattr(obs, "Registry", Spy)
+    request_q, response_q, stats_q = queue.Queue(), queue.Queue(), queue.Queue()
+    for i in range(3):
+        request_q.put(make_envelope(0, i, 0, 1, TopKRequest([i], k=3, score="pmi")))
+    request_q.put(serving._STOP)
+    cfg = serving.ServingConfig(workers=1, batch_window_ms=0.0)
+
+    events = _profile(tmp_path / "trace", lambda: serving._worker_main(
+        0, store_path, cfg, request_q, response_q, stats_q))
+
+    answers = []
+    while not response_q.empty():
+        msg = response_q.get()
+        if msg[0] != "claim":
+            answers.append(msg)
+    assert len(answers) == 3 and all(m[6] for m in answers)
+    by_start = sorted(events, key=lambda e: e[1])
+    batches = [e for e in by_start if e[0] == "serving/batch"]
+    assert [b[3] for b in batches] == [
+        {"seq": i, "requests": 1} for i in range(3)
+    ]
+    stages = [e for e in by_start if e[0].startswith("query/")]
+    assert [e[0] for e in stages] == [
+        "query/gather", "query/pad", "query/device",
+    ] * 3
+    for j, b in enumerate(batches):
+        for e in stages[3 * j:3 * j + 3]:
+            assert b[1] <= e[1] and e[2] <= b[2]
+    (reg,) = made
+    assert reg.log is False
+    assert reg.span_events() == [] and reg.dropped_events == 0
