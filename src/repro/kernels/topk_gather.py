@@ -1,12 +1,13 @@
 """Pallas TPU kernel: batched top-k neighbour selection on gathered CSR rows.
 
 The serving hot path (store/query.py) gathers each queried term's merged
-neighbour row from the mmap'd segments, pads the rows into a rectangular
-``(B, L)`` tile, and ranks the ``L`` candidates per row by count, PMI, or
-Dice. The reference implementation scores the tile and calls
-``jax.lax.top_k`` in one jitted function. Here XLA scores the tile with the
-reference's own expressions, fused with the padding, and one Pallas launch
-selects the top k, streaming the candidate axis through VMEM:
+neighbour row, on the device, from the int32 pages of its row cache into a
+rectangular ``(B, L)`` tile padded with id -1, and ranks the ``L``
+candidates per row by count, PMI, or Dice. The reference implementation
+scores the tile and calls ``jax.lax.top_k`` in one jitted function. Here
+XLA scores the tile with the reference's own expressions, fused with the
+padding, and one Pallas launch selects the top k, streaming the candidate
+axis through VMEM (``topk_gather`` takes the same tile as host arrays):
 
     for each (blk_b, BLK_L) column tile of ids and scores, in order:
         merge with the running top-k (k rounds of row-max,

@@ -15,6 +15,7 @@ the interpreter. This module keeps those rules in one place:
   instead of returning ``"cpu"`` on a host whose chip it failed to get;
 * :func:`use_kernels` / :func:`interpret` derive the kernel choice from the
   platform (compiled Pallas on a TPU, jnp references / interpreter off it);
+* :func:`memory_bytes` is the device's memory, where it reports it;
 * :func:`check_chip_owner` is called before spawning processes that need
   the chip and raises when more than one process would need it;
 * :func:`configure_compile_cache` places JAX's persistent compilation cache
@@ -80,6 +81,15 @@ def interpret() -> bool:
     """Whether Pallas kernels in this process run under the interpreter
     (every platform but the TPU)."""
     return platform() != "tpu"
+
+
+def memory_bytes() -> int | None:
+    """Bytes of memory of this process's first device, where the device
+    reports them (a TPU does; the CPU does not: ``None``)."""
+    import jax
+
+    stats = jax.devices()[0].memory_stats()
+    return int(stats["bytes_limit"]) if stats and "bytes_limit" in stats else None
 
 
 def check_chip_owner(processes: int, what: str) -> None:

@@ -418,8 +418,8 @@ def test_query_engine_spans_and_cache_counters(tmp_path, coll):
 @pytest.mark.parametrize("kernel", ["numpy", "pallas"])
 def test_query_engine_topk_stage_spans_in_order(tmp_path, coll, kernel):
     """One top-k launch emits query/gather, query/pad and query/device, one
-    after the other, inside its query/execute span (the pallas kernel runs
-    interpreted on the CPU)."""
+    after the other, inside its query/execute span, and query/upload inside
+    query/device (the pallas kernel runs interpreted on the CPU)."""
     from repro.core.cooc import count_to_store
     from repro.store import QueryEngine, TopKRequest
 
@@ -430,9 +430,17 @@ def test_query_engine_topk_stage_spans_in_order(tmp_path, coll, kernel):
     events = sorted(reg.span_events(), key=lambda e: e["ts_us"])
     assert [e["name"] for e in events] == [
         "query/execute", "query/gather", "query/pad", "query/device",
+        "query/upload",
     ]
-    root, stages = events[0], events[1:]
+    root, stages, upload = events[0], events[1:4], events[4]
     assert all(e["depth"] == root["depth"] + 1 for e in stages)
+    device = stages[-1]
+    assert upload["depth"] == device["depth"] + 1
+    assert upload["args"] == {"rows": 4}
+    assert device["ts_us"] <= upload["ts_us"]
+    assert upload["ts_us"] + upload["dur_us"] <= (
+        device["ts_us"] + device["dur_us"] + 1.0
+    )
     for a, b in zip(stages, stages[1:]):  # in order, never overlapping
         assert a["ts_us"] + a["dur_us"] <= b["ts_us"] + 1.0
     assert stages[-1]["ts_us"] + stages[-1]["dur_us"] <= (
@@ -571,7 +579,8 @@ def test_annotated_spans_reach_the_profiler(tmp_path):
 def test_serving_worker_spans_reach_the_profiler(tmp_path, coll, monkeypatch):
     """The serving worker's micro-batches show in a profile of its process
     as serving/batch annotations, each holding query/gather, query/pad and
-    query/device in that order; its registry keeps no span log."""
+    query/device (with query/upload inside) in that order; its registry
+    keeps no span log."""
     import queue
 
     from repro.core.cooc import count_to_store
@@ -611,11 +620,13 @@ def test_serving_worker_spans_reach_the_profiler(tmp_path, coll, monkeypatch):
     ]
     stages = [e for e in by_start if e[0].startswith("query/")]
     assert [e[0] for e in stages] == [
-        "query/gather", "query/pad", "query/device",
+        "query/gather", "query/pad", "query/device", "query/upload",
     ] * 3
     for j, b in enumerate(batches):
-        for e in stages[3 * j:3 * j + 3]:
+        for e in stages[4 * j:4 * j + 4]:
             assert b[1] <= e[1] and e[2] <= b[2]
+        device, upload = stages[4 * j + 2:4 * j + 4]
+        assert device[1] <= upload[1] and upload[2] <= device[2]
     (reg,) = made
     assert reg.log is False
     assert reg.span_events() == [] and reg.dropped_events == 0

@@ -78,3 +78,37 @@ def test_bitpair_compiles_at_default_blocks(one_chip):
     rows = ((1024, 512), jnp.uint32)
     _compile(functools.partial(bitpair_kernel, interpret=False), one_chip,
              rows, rows)
+
+
+# the served 65,536-term store's page pool: ids, counts and df of 4,096 rows
+# of 32 pages of 2,048 candidates
+POOL = ((2 + 4096 * 32, 3 * 2048), jnp.int32)
+
+
+@pytest.mark.parametrize("B, pages", [(1, 1), (64, 32)])
+def test_paged_topk_compiles_at_served_pool(one_chip, B, pages):
+    from repro.store.query import _topk_pages
+
+    fn = functools.partial(
+        _topk_pages, num_docs=10_000, score="pmi", k=10, kernel="pallas",
+        interpret=False,
+    )
+    i32 = jnp.int32
+    _compile(fn, one_chip, POOL, ((B, pages), i32), ((B,), i32))
+
+
+def test_page_upload_donates_the_pool(one_chip):
+    """The scatter aliases the pool's buffer to its output and copies it
+    not: an upload writes its pages in place."""
+    from repro.store.query import _upload_pages
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in (POOL, ((4,), jnp.int32), ((4, 3 * 2048), jnp.int32))]
+    compiled = _upload_pages.lower(*args).compile()
+    text = compiled.as_text()
+    assert "input_output_alias={ {}: (0, {}, may-alias) }" in text
+    pool_copies = [line for line in text.splitlines()
+                   if ("copy(" in line or "copy-start" in line)
+                   and f"s32[{POOL[0][0]},{POOL[0][1]}]" in line.split("=")[0]]
+    assert not pool_copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
